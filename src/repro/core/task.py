@@ -34,6 +34,8 @@ class StageProfile:
     payload: Optional[object] = None   # real-mode callable
     batch_gain: float = 1.0    # asymptotic batching speedup g_inf (Table I);
                                # 1.0 = batching scales work linearly
+    first_call_ms: float = 0.0  # real mode: the payload's first call
+                                # (trace + compile + one run)
 
 
 @dataclasses.dataclass

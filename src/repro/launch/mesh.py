@@ -8,19 +8,26 @@ everything else sees the real single CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axes, which the models'
+    # with_sharding_constraint calls cannot refer to
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_tiny_mesh(*, multi_pod: bool = False):
     """Reduced mesh for CI-sized subprocess tests (needs >= 8 devices)."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_partition_meshes(n_contexts: int, oversubscription: float = 1.0,

@@ -102,8 +102,10 @@ class StagedCNN:
         return x
 
 
-def build_resnet(depth: int = 18, *, seed: int = 0, n_classes: int = 100,
-                 width: int = 32) -> StagedCNN:
+def build_resnet(depth: int = 18, *, seed: int = 0, n_classes: int = 1000,
+                 width: int = 64) -> StagedCNN:
+    """ResNet-18/50 (He et al., 2016). The defaults are the published
+    ImageNet size: base width 64, 1000 classes, for 224x224x3 inputs."""
     ctx = InitCtx(jax.random.PRNGKey(seed), jnp.float32)
     basic = depth == 18
     blocks_per = {18: (2, 2, 2, 2), 50: (3, 4, 6, 3)}[depth]

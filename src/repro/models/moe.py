@@ -183,13 +183,7 @@ def moe_ep_shardmap(params: dict, x: jax.Array, *, topk: int, mesh,
     EP adds no extra collective.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.shard_map import shard_map          # jax >= 0.9
-    except ImportError:
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     e_padded = params["experts"]["w_gate"].shape[0]
     tp = mesh.shape[tp_axis]
@@ -223,5 +217,5 @@ def moe_ep_shardmap(params: dict, x: jax.Array, *, topk: int, mesh,
     fn = shard_map(local_fn, mesh=mesh,
                    in_specs=(P(), expert_specs, x_spec),
                    out_specs=(x_spec, P()),
-                   check_rep=False)
+                   check_vma=False)
     return fn(params["router"], params["experts"], x)

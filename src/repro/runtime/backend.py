@@ -434,11 +434,11 @@ class SimBackend:
 def _default_input_factory(input_hw: int, batch: int) -> Callable[[Job], object]:
     """Image-shaped zero input matching the staged-CNN payload convention.
     A dynamically batched job widens the leading axis by ``n_inputs`` so
-    the whole batch rides through the staged payload in one dispatch."""
+    the whole batch rides through the staged payload in one dispatch. The
+    backend places it on the job's context (``RealtimeBackend._worker``)."""
     def make(job: Job):
-        import jax
-        return jax.device_put(np.zeros(
-            (batch * job.n_inputs, input_hw, input_hw, 3), np.float32))
+        return np.zeros((batch * job.n_inputs, input_hw, input_hw, 3),
+                        np.float32)
     return make
 
 
@@ -462,21 +462,14 @@ class _WorkerPool:
             self._threads.append(t)
 
     def _loop(self) -> None:
+        # ``fn`` reports its own failures (RealtimeBackend._worker ships
+        # them to the engine thread), so nothing here can kill the worker
         while True:
             item = self._q.get()
             if item is None:
                 return
             fn, lane, inst = item
-            try:
-                fn(lane, inst)
-            except Exception as e:   # noqa: BLE001 — worker must survive
-                # a raising payload loses that stage (exactly what the old
-                # thread-per-stage design did) but must not kill the
-                # worker: a dead worker would starve every later stage
-                # queued to the pool
-                import sys
-                print(f"worker: stage {getattr(inst.task, 'name', '?')} "
-                      f"on lane {lane} raised {e!r}", file=sys.stderr)
+            fn(lane, inst)
 
     def submit(self, fn, lane: tuple, inst: StageInstance) -> None:
         self._q.put((fn, lane, inst))
@@ -609,8 +602,12 @@ class RealtimeBackend:
                     item = self._done_q.get(timeout=timeout_s)
             except queue.Empty:
                 return []
-            lane, inst, et, out, token, failed = item
+            lane, inst, et, out, token, failed, exc = item
             self._inflight -= 1
+            if exc is not None:
+                raise RuntimeError(
+                    f"stage {inst.profile.name} of {inst.task.name} on lane "
+                    f"{lane} raised") from exc
             if lane[0] in self._cancelled_ctx:
                 # ghost completion from a failed context: fail_context
                 # already re-enqueued the instance, and dead contexts never
@@ -653,7 +650,7 @@ class RealtimeBackend:
         """Reshard inter-stage state produced on another context onto this
         context's partition (zero-delay: between stage programs)."""
         src = self._state_ctx.get(job_id, ctx)
-        if x is None or src == ctx:
+        if src == ctx:
             return x
         tgt = self._sharding_for(ctx)
         if tgt is None:
@@ -667,28 +664,33 @@ class RealtimeBackend:
                 failed: bool = False) -> None:
         prof = inst.profile
         t0 = time.perf_counter()
-        if stall_ms:
-            # chaos-injected lane stall (driver hiccup / ECC scrub): the
-            # stage runs, just late — the stall serializes ahead of it
-            time.sleep(stall_ms / 1000.0)
-        if prof.payload is None:
-            # synthetic stage: sleep the batched work (b/g(b) scaling)
-            time.sleep(batched_stage_ms(prof, inst.job.n_inputs) / 1000.0)
-            out = self._job_state.get(inst.job.job_id)
-        else:
-            x = self._job_state.get(inst.job.job_id)
-            if x is None:
-                x = self.input_factory(inst.job)
+        out, exc = None, None
+        try:
+            if stall_ms:
+                # chaos-injected lane stall (runtime hiccup / ECC scrub): the
+                # stage runs, just late — the stall serializes ahead of it
+                time.sleep(stall_ms / 1000.0)
+            if prof.payload is None:
+                # synthetic stage: sleep the batched work (b/g(b) scaling)
+                time.sleep(batched_stage_ms(prof, inst.job.n_inputs) / 1000.0)
+                out = self._job_state.get(inst.job.job_id)
             else:
-                x = self._migrate_state(x, inst.job.job_id, lane[0])
-            out = prof.payload(x)
-            try:
                 import jax
+                x = self._job_state.get(inst.job.job_id)
+                if x is None:
+                    # a fresh job starts on its own context's device,
+                    # committed there like the arrays payloads calibrate on
+                    x = jax.device_put(self.input_factory(inst.job),
+                                       self._sharding_for(lane[0])
+                                       or jax.devices()[0])
+                else:
+                    x = self._migrate_state(x, inst.job.job_id, lane[0])
+                out = prof.payload(x)
                 jax.block_until_ready(out)
-            except ImportError:
-                pass
+        except Exception as e:  # noqa: BLE001 — re-raised by advance()
+            exc = e
         et_ms = (time.perf_counter() - t0) * 1000.0
-        self._done_q.put((lane, inst, et_ms, out, token, failed))
+        self._done_q.put((lane, inst, et_ms, out, token, failed, exc))
 
     def launch(self, lane: tuple, inst: StageInstance) -> None:
         self._inflight += 1

@@ -311,8 +311,12 @@ class EngineCore:
 
     def run(self, until_idle: bool = False) -> RunMetrics:
         self._begin()
-        while self._step(until_idle, None):
-            pass
+        try:
+            while self._step(until_idle, None):
+                pass
+        except BaseException:
+            self.backend.stop()     # e.g. a stage payload raised
+            raise
         return self._finalize()
 
     # ------------------------------------------------------- serving mode

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import time
 import warnings
-from typing import Callable, List
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -34,33 +34,56 @@ from ..runtime.engine_core import EngineCore
 __all__ = ["RealtimeEngine", "staged_cnn_taskspec", "staged_lm_taskspec"]
 
 
+def _timed_call(fn: Callable, x) -> tuple:
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(x))
+    return out, (time.perf_counter() - t0) * 1000.0
+
+
 def staged_cnn_taskspec(model: StagedCNN, *, priority: int, jps: float,
                         input_hw: int = 64, batch: int = 1,
                         tag: str = "", calibrate: bool = True,
-                        n_sat: float = 40.0, mem_frac: float = 0.4) -> TaskSpec:
+                        n_sat: float = 40.0, mem_frac: float = 0.4,
+                        devices: Optional[Sequence] = None) -> TaskSpec:
     """Wrap a StagedCNN into a TaskSpec whose stage payloads are jitted
-    callables; t_alone is measured on this machine (AFET-style)."""
+    callables; t_alone is measured on this machine (AFET-style).
+
+    The weights are placed once on each of ``devices`` (default: the
+    first device). A payload runs on the device its input lives on, with
+    that device's copy of the weights, so a job the backend migrates to
+    another chip computes there without moving weights. Calibration
+    compiles every stage for every device, records the first call on the
+    first device as ``first_call_ms`` and times a second call there as
+    ``t_alone_ms``."""
+    devices = list(devices or jax.devices()[:1])
+    params_on = {d: jax.device_put(model.params, d) for d in devices}
+
+    def make_payload(st):
+        def payload(x):     # x: an image, or UNet's (x, skips) tuple
+            return st(params_on[jax.tree.leaves(x)[0].device], x)
+        return payload
+
     x0 = np.zeros((batch, input_hw, input_hw, 3), np.float32)
-    jitted = [jax.jit(st) for st in model.stages]
+    states = {d: jax.device_put(x0, d) for d in devices}
     payloads: List[Callable] = []
-    times = []
-    state = jax.device_put(x0)
-    for st in jitted:
-        fn = (lambda s, st=st: st(model.params, s))
+    first, times = [], []
+    for stage in model.stages:
+        fn = make_payload(jax.jit(stage))
         if calibrate:
-            out = fn(state)
-            jax.block_until_ready(out)           # compile
-            t0 = time.perf_counter()
-            out = fn(state)
-            jax.block_until_ready(out)
-            times.append((time.perf_counter() - t0) * 1000.0)
-            state = out
+            outs = {}
+            for d in devices:
+                outs[d], ms = _timed_call(fn, states[d])
+                if d == devices[0]:
+                    first.append(ms)
+            times.append(_timed_call(fn, states[devices[0]])[1])
+            states = outs
         payloads.append(fn)
     if not calibrate:
+        first = [0.0] * len(payloads)
         times = [1.0] * len(payloads)
     stages = [StageProfile(name=f"{model.name}/s{j}", t_alone_ms=t,
                            n_sat=n_sat, mem_frac=mem_frac, overhead_ms=0.05,
-                           payload=payloads[j])
+                           payload=payloads[j], first_call_ms=first[j])
               for j, t in enumerate(times)]
     return TaskSpec(name=f"{model.name}{tag}", period_ms=1000.0 / jps,
                     priority=priority, stages=stages, batch=batch)

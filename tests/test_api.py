@@ -84,6 +84,28 @@ def test_sim_and_realtime_backends_make_identical_decisions():
     assert m_sim.rejected == m_real.rejected
 
 
+def test_realtime_payload_exception_raises_from_run():
+    """A stage payload that raises (a program that fails to compile or
+    runs out of device memory) surfaces from run() at its harvest; the
+    run neither hangs nor returns as if the stage had merely vanished."""
+    import time
+
+    def broken(x):
+        raise ValueError("payload failed")
+
+    spec = TaskSpec(name="broken", period_ms=50.0, priority=HP,
+                    stages=[StageProfile("broken/s0", 1.0, n_sat=1.0,
+                                         mem_frac=0.0, payload=broken)])
+    srv = (ServerConfig.realtime().tasks([spec]).contexts(1)
+           .horizon_ms(60_000.0).realtime_io(input_hw=4).build())
+    t0 = time.perf_counter()
+    with pytest.raises(RuntimeError, match="broken/s0") as err:
+        srv.run()
+    assert isinstance(err.value.__cause__, ValueError)
+    assert time.perf_counter() - t0 < 30.0     # well before the horizon
+    assert srv.backend._pool._threads == []     # workers were stopped
+
+
 # ------------------------------------------------------- poisson arrivals
 def _poisson_run(seed):
     srv = (ServerConfig.sim()

@@ -1,5 +1,11 @@
 """End-to-end behaviour tests: the paper's headline claims hold in-sim,
 checkpoint round-trips, data pipeline resume, real-execution engine."""
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -151,3 +157,101 @@ def test_realtime_engine_with_cnn_stages():
     m = eng.run()
     assert m.completed[HP] > 0
     assert m.resp_stats(HP)["mean"] > 0
+
+
+# ------------------------------------------------- chip entry points (CPU)
+_ROOT = Path(__file__).resolve().parents[1]
+
+
+def _python(code: str, tmp_path, **env) -> subprocess.CompletedProcess:
+    """Run ``code`` in a fresh interpreter, so that JAX flags and config
+    set there never reach this test process."""
+    full_env = {**os.environ, "PYTHONPATH": str(_ROOT / "src"),
+                "JAX_PLATFORMS": "cpu", **env}
+    return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                          env=full_env, cwd=tmp_path, capture_output=True,
+                          text=True, timeout=240)
+
+
+def test_chip_smoke_refuses_cpu(tmp_path):
+    """Without a TPU the smoke exits non-zero before building anything and
+    prints no result line."""
+    r = subprocess.run([sys.executable, str(_ROOT / "chip_smoke.py")],
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       cwd=tmp_path, capture_output=True, text=True,
+                       timeout=120)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert r.stdout == ""
+
+
+def test_compile_cache_follows_env_else_checkout(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, entries land there and the
+    program sets no directory of its own; unset, the cache directory is
+    the fixed <checkout>/.jax_cache."""
+    probe = """
+        import jax, jax.numpy as jnp
+        from repro.launch.serve import use_compile_cache
+        use_compile_cache()
+        print(jax.config.jax_compilation_cache_dir)
+        print(jax.config.jax_persistent_cache_min_compile_time_secs)
+        if {compile}:
+            jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()
+    """
+    cache = tmp_path / "cache"
+    r = _python(probe.format(compile=True), tmp_path,
+                JAX_COMPILATION_CACHE_DIR=str(cache))
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == [str(cache), "0.0"]
+    assert any(cache.iterdir())
+    r = _python(probe.format(compile=False), tmp_path,
+                JAX_COMPILATION_CACHE_DIR="")
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == [str(_ROOT / ".jax_cache"), "0.0"]
+
+
+def test_realtime_contexts_pinned_to_devices(tmp_path):
+    """Two host devices stand in for two chips: context k runs on device
+    k, fresh jobs start on their context's device, and a repartition
+    reshards in-flight state across devices without changing a logit."""
+    code = """
+        import jax, numpy as np
+        from repro.launch.serve import realtime_config
+        from repro.models.cnn import build_resnet
+        devs = jax.devices()
+        assert len(devs) == 2
+        image = np.random.default_rng(0).standard_normal(
+            (1, 64, 64, 3)).astype(np.float32)
+        model = build_resnet(18, width=8, n_classes=10)
+        cfg, specs = realtime_config([model, model], contexts=2,
+                                     seconds=1.5, hw=64,
+                                     input_factory=lambda job: image)
+        ref = jax.device_put(image, devs[0])
+        for st in specs[0].stages:
+            ref = st.payload(ref)
+        ref = np.asarray(ref)
+        seen, logits = set(), []
+        def observed(payload, last):
+            def run(x):
+                out = payload(x)
+                seen.add(out.device)
+                if last:
+                    logits.append(np.asarray(out))
+                return out
+            return run
+        for spec in specs:
+            for j, st in enumerate(spec.stages):
+                st.payload = observed(st.payload, j == 3)
+        for t in range(5):
+            cfg.reconfigure_at(200.0 * (t + 1) + 2.0 * (t + 1), n_contexts=2)
+        srv = cfg.build()
+        m = srv.run()
+        assert seen == set(devs), seen
+        assert m.completed[0] > 0 and m.completed[1] > 0
+        assert all(np.array_equal(x, ref) for x in logits)
+        print(srv.backend.resharded)
+    """
+    r = _python(code, tmp_path,
+                XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert int(r.stdout.split()[-1]) > 0        # state moved between devices
