@@ -27,7 +27,8 @@ know about:
   scan, but the intent must be declared (``# dsan: ignore[DSAN005]``)
   or an O(1) identity container used instead.
 * **DSAN006** — a call through an optional hook attribute
-  (``self._sanitizer.…(...)`` / ``self._chaos.…(...)``) that no
+  (``self._sanitizer.…(...)`` / ``self._chaos.…(...)`` /
+  ``self._tracer.…(...)``) that no
   enclosing ``is not None`` check guards. The twin-path zero-overhead
   contract keeps these hooks ``None`` unless opted in; an unguarded
   call is an AttributeError waiting for the default path.
@@ -100,7 +101,7 @@ _DETERMINISTIC = re.compile(
     r"(^|[/\\])(core|cluster)[/\\]|[/\\]runtime[/\\]engine_core\.py$")
 
 # optional hook attributes gated by the twin-path contract (DSAN006)
-_HOOK_ATTRS = frozenset(("_sanitizer", "_chaos"))
+_HOOK_ATTRS = frozenset(("_sanitizer", "_chaos", "_tracer"))
 
 # chaos code must draw from its own seeded streams (DSAN007)
 _CHAOS_PATH = re.compile(r"(^|[/\\])chaos[/\\]")

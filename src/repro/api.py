@@ -48,6 +48,7 @@ from .runtime.arrivals import (ArrivalProcess, ManualArrival,
 from .runtime.backend import (ExecutionBackend, RealtimeBackend, SimBackend)
 from .runtime.contention import DeviceModel
 from .runtime.epoch import EpochSimBackend
+from .runtime.trace import Tracer
 from .runtime.engine_core import (AutoscalePolicy, Completion, EngineCore,
                                   FaultPlan, SubmitHandle)
 
@@ -93,6 +94,7 @@ class ServerConfig:
         self._record_decisions = False
         self._sanitize = None
         self._chaos_plan: Optional[ChaosPlan] = None
+        self._trace = False
         self._input_hw = 64
         self._batch = 1
         self._input_factory = None
@@ -257,6 +259,15 @@ class ServerConfig:
         ``SanitizerViolation``."""
         from .analysis.sanitizer import Sanitizer
         self._sanitize = Sanitizer(level=level, cadence=cadence)
+        return self
+
+    def trace(self, enabled: bool = True) -> "ServerConfig":
+        """Keep the in-program tracer's records and counters
+        (``repro.runtime.trace``): a timestamp tuple per stage execution
+        and per engine-loop step, admission and engine-loop counters in
+        ``snapshot()["trace"]``. Profiler spans stay off until
+        ``server.tracer.annotate(True)``."""
+        self._trace = enabled
         return self
 
     # ------------------------------------------------------ faults/elastic
@@ -608,7 +619,8 @@ class DarisServer:
             seed=cfg._seed, arrivals=arrivals, fault_plan=cfg._fault_plan,
             autoscale=cfg._autoscale,
             record_decisions=cfg._record_decisions,
-            sanitize=cfg._sanitize, chaos=cfg._chaos_plan)
+            sanitize=cfg._sanitize, chaos=cfg._chaos_plan,
+            trace=cfg._trace)
 
     # ------------------------------------------------------------- serving
     def run(self) -> RunMetrics:
@@ -704,6 +716,11 @@ class DarisServer:
     @property
     def metrics(self) -> RunMetrics:
         return self.core.metrics
+
+    @property
+    def tracer(self) -> Optional[Tracer]:
+        """The in-program tracer (``ServerConfig.trace()``), else None."""
+        return self.core._tracer
 
     @property
     def decisions(self) -> Optional[List[str]]:

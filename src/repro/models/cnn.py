@@ -88,6 +88,15 @@ def _bottleneck_apply(p, x, stride):
     return jax.nn.relu(y + sc)
 
 
+def _named(model: str, stages: List[Callable]) -> List[Callable]:
+    """Name stage j ``<model>_s<j>``. jit names the stage's program after
+    it (``jit_resnet18_s0``), which is how a profiler trace tells the
+    stage programs apart."""
+    for j, fn in enumerate(stages):
+        fn.__name__ = fn.__qualname__ = f"{model}_s{j}"
+    return stages
+
+
 @dataclasses.dataclass
 class StagedCNN:
     name: str
@@ -143,7 +152,8 @@ def build_resnet(depth: int = 18, *, seed: int = 0, n_classes: int = 1000,
         return fn
 
     return StagedCNN(name=f"resnet{depth}", params=params,
-                     stages=[make_stage(i) for i in range(4)],
+                     stages=_named(f"resnet{depth}",
+                                   [make_stage(i) for i in range(4)]),
                      n_classes=n_classes)
 
 
@@ -204,7 +214,7 @@ def build_unet(*, seed: int = 0, width: int = 24) -> StagedCNN:
         return conv(x, p["out"])
 
     return StagedCNN(name="unet", params=params,
-                     stages=[stage0, stage1, stage2, stage3])
+                     stages=_named("unet", [stage0, stage1, stage2, stage3]))
 
 
 # ------------------------------------------------------------ InceptionV3
@@ -265,7 +275,8 @@ def build_inception(*, seed: int = 0, width: int = 24,
         return x @ p["head"]
 
     return StagedCNN(name="inceptionv3", params=params,
-                     stages=[stage0, stage1, stage2, stage3])
+                     stages=_named("inceptionv3",
+                                   [stage0, stage1, stage2, stage3]))
 
 
 BUILDERS = {

@@ -457,7 +457,8 @@ class _WorkerPool:
 
     def ensure(self, n: int) -> None:
         while len(self._threads) < n:
-            t = threading.Thread(target=self._loop, daemon=True)
+            t = threading.Thread(target=self._loop, daemon=True,
+                                 name=f"daris-worker-{len(self._threads)}")
             t.start()
             self._threads.append(t)
 
@@ -552,6 +553,7 @@ class RealtimeBackend:
         self._live_token: Dict[tuple, int] = {}
         self._t0 = 0.0
         self._pool = _WorkerPool()
+        self._tracer = None     # the engine's, bound at bind(); None = off
         # pool sizing is by LIVE lane count (plus in-flight stages on
         # retired lanes), recomputed only when the lane table grows: a
         # reconfigure-heavy run accumulates retired lanes forever, and
@@ -562,6 +564,7 @@ class RealtimeBackend:
     # ----------------------------------------------------------- lifecycle
     def bind(self, core: EngineCore) -> None:
         self.core = core
+        self._tracer = core._tracer
 
     def _ensure_pool(self) -> None:
         """Grow the worker pool to one worker per live lane (+ stages
@@ -602,7 +605,7 @@ class RealtimeBackend:
                     item = self._done_q.get(timeout=timeout_s)
             except queue.Empty:
                 return []
-            lane, inst, et, out, token, failed, exc = item
+            lane, inst, et, out, token, failed, exc, marks = item
             self._inflight -= 1
             if exc is not None:
                 raise RuntimeError(
@@ -624,7 +627,7 @@ class RealtimeBackend:
                 # it over the job's last good inter-stage state
                 self._job_state[inst.job.job_id] = out
                 self._state_ctx[inst.job.job_id] = lane[0]
-            return [Completion(lane, inst, et, failed)]
+            return [Completion(lane, inst, et, failed, marks)]
 
     def peek_eta(self) -> float:
         """Wall clock: in-flight work can complete at any instant, so the
@@ -646,15 +649,19 @@ class RealtimeBackend:
                 return self.ctx_shardings.get(slot)
         return None      # retired context: never reshard onto it
 
-    def _migrate_state(self, x: object, job_id: int, ctx: int) -> object:
+    def _migrate_state(self, x: object, job_id: int, ctx: int,
+                       wm=None) -> object:
         """Reshard inter-stage state produced on another context onto this
-        context's partition (zero-delay: between stage programs)."""
+        context's partition (zero-delay: between stage programs). ``wm``:
+        the worker's trace marks, None when tracing is off."""
         src = self._state_ctx.get(job_id, ctx)
         if src == ctx:
             return x
         tgt = self._sharding_for(ctx)
         if tgt is None:
             return x
+        if wm is not None:
+            wm.span("daris.reshard")
         from ..serving.staging import migrate
         self.resharded += 1
         return migrate(x, tgt)
@@ -663,6 +670,9 @@ class RealtimeBackend:
                 token=None, stall_ms: float = 0.0,
                 failed: bool = False) -> None:
         prof = inst.profile
+        wm = None
+        if self._tracer is not None:
+            wm = self._tracer.worker(inst)      # stamps the pickup
         t0 = time.perf_counter()
         out, exc = None, None
         try:
@@ -678,19 +688,29 @@ class RealtimeBackend:
                 import jax
                 x = self._job_state.get(inst.job.job_id)
                 if x is None:
+                    if wm is not None:
+                        wm.span("daris.upload")
                     # a fresh job starts on its own context's device,
                     # committed there like the arrays payloads calibrate on
                     x = jax.device_put(self.input_factory(inst.job),
                                        self._sharding_for(lane[0])
                                        or jax.devices()[0])
                 else:
-                    x = self._migrate_state(x, inst.job.job_id, lane[0])
+                    x = self._migrate_state(x, inst.job.job_id, lane[0],
+                                            wm)
+                if wm is not None:
+                    wm.mark("daris.issue")          # input ready
                 out = prof.payload(x)
+                if wm is not None:
+                    wm.mark("daris.sync")           # device work issued
                 jax.block_until_ready(out)
+                if wm is not None:
+                    wm.mark()                       # synced
         except Exception as e:  # noqa: BLE001 — re-raised by advance()
             exc = e
         et_ms = (time.perf_counter() - t0) * 1000.0
-        self._done_q.put((lane, inst, et_ms, out, token, failed, exc))
+        marks = wm.finish() if wm is not None else None
+        self._done_q.put((lane, inst, et_ms, out, token, failed, exc, marks))
 
     def launch(self, lane: tuple, inst: StageInstance) -> None:
         self._inflight += 1

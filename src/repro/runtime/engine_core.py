@@ -36,6 +36,7 @@ from ..core.metrics import RunMetrics, empty_metrics, tenant_stats
 from ..core.scheduler import DarisScheduler, Rejection
 from ..core.task import HP, LP, Job, StageInstance, Task, TaskSpec
 from .arrivals import ArrivalProcess
+from .trace import Tracer
 
 _seq = itertools.count()
 
@@ -132,11 +133,13 @@ class Completion:
     marks a chaos-injected transient stage fault: the full execution
     time was paid but the result is garbage — the engine must retry or
     abort instead of advancing the pipeline. Always False with no
-    ``ChaosPlan`` installed."""
+    ``ChaosPlan`` installed. ``marks``: the worker's instants
+    (``trace.WorkerMarks``) when tracing is on, else None."""
     lane: tuple
     inst: StageInstance
     et_ms: float
     failed: bool = False
+    marks: Optional[tuple] = None
 
 
 class SubmitHandle:
@@ -211,7 +214,7 @@ class EngineCore:
                  fault_plan: Optional[FaultPlan] = None,
                  autoscale: Optional[AutoscalePolicy] = None,
                  record_decisions: bool = False,
-                 sanitize=None, chaos=None):
+                 sanitize=None, chaos=None, trace: bool = False):
         self.sched = sched
         self.backend = backend
         self.horizon = horizon_ms
@@ -250,6 +253,10 @@ class EngineCore:
         # DSAN invariant auditor (analysis/sanitizer.py); None when off —
         # the hook sites below are then a bare attribute test
         self._sanitizer = _resolve_sanitizer(sanitize)
+        # in-program tracer (runtime/trace.py); None when off — each site
+        # below is then a bare attribute test, as for the sanitizer
+        self._tracer: Optional[Tracer] = (Tracer(backend.now_ms) if trace
+                                          else None)
 
     # ------------------------------------------------------------ plumbing
     def _push(self, t: float, kind: int, payload) -> None:
@@ -413,11 +420,18 @@ class EngineCore:
         cap = min(t_evt, self.horizon)
         if frontier is not None and not self.backend.virtual_time:
             cap = min(cap, frontier)   # wall clock: don't block past it
+        if self._tracer is not None:
+            self._tracer.wait_begin(cap)
         completions = self.backend.advance(cap)
         now = self.backend.now_ms()
+        if self._tracer is not None:
+            self._tracer.woke(now, bool(completions))
         if completions:
             for c in completions:
-                self._on_completion(c)
+                if self._tracer is not None:
+                    self._tracer.harvest(c, now, self._on_completion)
+                else:
+                    self._on_completion(c)
         elif (self._timeline and t_evt <= self.horizon
               and now >= t_evt - 1e-6):
             t, kind, seq, payload = heapq.heappop(self._timeline)
@@ -452,12 +466,16 @@ class EngineCore:
             return False
         elif not self._timeline and not self.backend.has_inflight():
             return False    # nothing can ever happen again
+        if self._tracer is not None:
+            self._tracer.handled()
         # tell the scheduler when this loop is guaranteed to run again
         # (lazy batch-head holds must release before then)
         self.sched.next_wake_ms = (self._timeline[0][0]
                                    if self._timeline else math.inf)
         self._dispatch()
         self.backend.running_set_changed()
+        if self._tracer is not None:
+            self._tracer.step_end()
         if self._sanitizer is not None:
             self._sanitizer.after_step(self)
         return True
@@ -537,17 +555,24 @@ class EngineCore:
                 self._sanitizer.note_release(LP, "rejected")
         else:
             pre_coalesced = self.sched.coalesced
-            job = self.sched.on_release(task, now)
+            if self._tracer is not None:
+                job = self._tracer.release(task, now, self.sched.on_release)
+            else:
+                job = self.sched.on_release(task, now)
+            # the decision log's strings are built only when it is kept:
+            # this is the one engine thread's hot path
             if job is None:
-                self._log(f"reject {task.name}")
+                if self.decisions is not None:
+                    self.decisions.append(f"reject {task.name}")
                 if handle is not None:
                     handle.status = SubmitHandle.REJECTED
             else:
-                if self.sched.coalesced > pre_coalesced:
-                    self._log(f"batch {task.name} -> ctx{job.ctx} "
-                              f"b={job.n_inputs}")
-                else:
-                    self._log(f"admit {task.name} -> ctx{job.ctx}")
+                if self.decisions is not None:
+                    self.decisions.append(
+                        f"batch {task.name} -> ctx{job.ctx} "
+                        f"b={job.n_inputs}"
+                        if self.sched.coalesced > pre_coalesced
+                        else f"admit {task.name} -> ctx{job.ctx}")
                 if handle is not None:
                     handle.status = SubmitHandle.QUEUED
                     handle.job = job
@@ -908,7 +933,8 @@ class EngineCore:
             self._on_stage_failed(c, now)
             return
         done = self.sched.on_stage_finish(c.inst, now, c.et_ms)
-        self._log(f"finish {job.task.name} s{stage}")
+        if self.decisions is not None:
+            self.decisions.append(f"finish {job.task.name} s{stage}")
         if done is None:
             return
         self.backend.on_job_done(done)
@@ -999,9 +1025,14 @@ class EngineCore:
                 for h in self._job_handles.get(inst.job.job_id, ()):
                     if h.status == SubmitHandle.QUEUED:
                         h.status = SubmitHandle.RUNNING
-            self._log(f"dispatch {inst.task.name} s{inst.job.stage_idx} "
-                      f"lane({lane[0]},{lane[1]})")
-            self.backend.launch(lane, inst)
+            if self.decisions is not None:
+                self.decisions.append(
+                    f"dispatch {inst.task.name} s{inst.job.stage_idx} "
+                    f"lane({lane[0]},{lane[1]})")
+            if self._tracer is not None:
+                self._tracer.dispatch(lane, inst, self.backend.launch)
+            else:
+                self.backend.launch(lane, inst)
             if (self._chaos is not None
                     and self._chaos.plan.watchdog_kappa > 0.0
                     and inst.smret is not None):
@@ -1057,6 +1088,8 @@ class EngineCore:
             "resp_lp": self.metrics.resp_stats(LP),
             "cancelled": dict(self.metrics.cancelled),
         }
+        if self._tracer is not None:
+            snap["trace"] = self._tracer.counters()
         if any(h.tenant is not None for h in self._all_handles):
             snap["tenants"] = tenant_stats(self._all_handles)
         summary = getattr(self.sched, "device_summary", None)

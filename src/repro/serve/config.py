@@ -14,13 +14,17 @@ Config schema (all scheduler fields optional)::
       ],
       "contexts": 4, "streams": 1, "oversubscribe": 4.0,
       "batching": {"max_batch": 8, "scope": "model"},
-      "seed": 0, "noise": 0.06, "horizon_ms": 1e9
+      "seed": 0, "noise": 0.06, "horizon_ms": 1e9,
+      "trace": false
     }
 
 ``dnn`` names a calibrated profile (``serving.profiles``: resnet18, unet,
 inceptionv3). Every task gets a ``ManualArrival`` — the daemon's clients
 are the only release source — unless ``"jps_background": true`` marks it
 as self-releasing periodic load behind the served traffic.
+``"trace": true`` keeps the engine's tracer on, so the ``stats`` verb's
+snapshot carries its admission and engine-loop counters under
+``"trace"``.
 """
 from __future__ import annotations
 
@@ -107,6 +111,8 @@ def server_config(cfg: Dict, *, arrivals: Optional[Dict[str, object]] = None
         # same dict shape ChaosPlan takes; see chaos.plan.plan_from_dict
         from ..chaos.plan import plan_from_dict
         sc.chaos(plan_from_dict(c))
+    if cfg.get("trace"):
+        sc.trace()
     s = cfg.get("sanitize")
     if s:
         # {"sanitize": 2} or {"sanitize": {"level": 1, "cadence": 64}};

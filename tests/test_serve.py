@@ -226,6 +226,29 @@ def test_daemon_round_trip(tmp_path):
     assert audit_zero_lost(read_journal(tmp_path / "journal.jsonl")) == []
 
 
+def test_stats_verb_shows_trace_counters_when_config_asks(tmp_path,
+                                                           capsys):
+    """``"trace": true`` in the serving config keeps the engine's tracer
+    on, and ``python -m repro.serve stats`` shows its counters."""
+    from repro.serve.__main__ import main
+    assert "trace" not in build_server(daemon_cfg()).snapshot()
+    d, th, c = start_daemon(tmp_path, cfg=daemon_cfg(trace=True),
+                            time_scale=200.0, tick_ms=1.0)
+    s = c.submit("resnet18", tenant="teamA")
+    assert c.result(s["seq"], timeout_s=30.0)["status"] in ("completed",
+                                                            "missed")
+    capsys.readouterr()
+    assert main(["stats", "--socket", d.socket_path]) == 0
+    trace = json.loads(capsys.readouterr().out)["snapshot"]["trace"]
+    assert trace["admitted"]["hp"] == 1 and trace["refused"]["lp"] == 0
+    assert trace["records"]["stages"] >= 1
+    assert {"wait_ms", "step_ms", "timeouts", "late_ms", "longest_steps",
+            "longest_late_wakes"} <= set(trace["engine"])
+    c.drain()
+    th.join(timeout=10.0)
+    assert not th.is_alive()
+
+
 def test_daemon_cancel_round_trip(tmp_path):
     # virtual time frozen at ticks: submissions stay queued long enough
     # to be cancelled deterministically
