@@ -51,6 +51,22 @@ def test_lp_tail_reads_when_every_lp_release_is_refused():
     assert load("lp_p95_ms")(run) == pytest.approx(480 + 0.95 * 10)
 
 
+@pytest.mark.parametrize("case, value", [
+    (req("lp", 40.0, 40.0, 73.0), 100.0),                 # met in time
+    (req("lp", 40.0, 40.0, 73.5, "missed"), 50.0),        # finished late
+    (req("lp", 40.0, None, None, "rejected"), 50.0),      # refused
+    (req("lp", 40.0, 40.0, None, "running"), 50.0),       # never finished
+    (req("hp", 40.0, 40.0, 45.0), None),                  # no LP release
+], ids=["met", "late", "refused", "unfinished", "no_lp"])
+def test_lp_met_share(case, value):
+    # an HP job that missed does not count among LP releases
+    reqs = [req("hp", 0.0, 0.0, 90.0, "missed"), case]
+    if value is not None:
+        reqs.append(req("lp", 10.0, 10.0, 20.0))
+    got = load("lp_met_share")(run_of(reqs, end_ms=500.0))
+    assert got == (None if value is None else pytest.approx(value))
+
+
 def test_admit_share_and_stage_gap():
     reqs = [req("lp", 0.0, 0.0, 9.0), req("lp", 1.0, None, None, "rejected")]
     stages = [(7, 0, 0, 1.0, 2.0), (7, 0, 1, 2.5, 3.0), (7, 0, 2, 3.5, 4.0),
