@@ -67,6 +67,25 @@ def test_lp_met_share(case, value):
     assert got == (None if value is None else pytest.approx(value))
 
 
+@pytest.mark.parametrize("case, value", [
+    (req("hp", 40.0, 40.0, 45.0), 5.0),                   # on time
+    (req("hp", 40.0, 42.0, 80.0, "missed"), 40.0),        # late
+    (req("hp", 40.0, 40.0, None, "running"), 460.0),      # never finished
+    (req("lp", 40.0, 40.0, 45.0), 500.0),                 # LP ignored
+    (req("lp", 40.0, None, None, "rejected"), None),      # no HP release
+], ids=["on_time", "late", "unfinished", "lp_ignored", "no_hp"])
+def test_hp_p50_ms(case, value):
+    # beside the case, HP jobs 1 ms and 999 ms from their due times: an HP
+    # case's latency is the median of the three, an LP one leaves the
+    # median of the two; the late LP job never counts
+    reqs = [req("lp", 0.0, 0.0, 300.0, "missed"), case]
+    if value is not None:
+        reqs += [req("hp", 10.0, 10.0, 11.0),
+                 req("hp", 20.0, 20.0, 1019.0, "missed")]
+    got = load("hp_p50_ms")(run_of(reqs, end_ms=500.0))
+    assert got == (None if value is None else pytest.approx(value))
+
+
 def test_admit_share_and_stage_gap():
     reqs = [req("lp", 0.0, 0.0, 9.0), req("lp", 1.0, None, None, "rejected")]
     stages = [(7, 0, 0, 1.0, 2.0), (7, 0, 1, 2.5, 3.0), (7, 0, 2, 3.5, 4.0),
